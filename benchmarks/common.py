@@ -18,6 +18,14 @@ from repro.core.scheduler import SimConfig
 
 OUT_DIR = "experiments/bench"
 
+#: the checkout root (benchmarks/..)
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: JAX's persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset:
+#: a fixed path (gitignored), so a later process finds what an earlier one
+#: compiled
+JAX_CACHE_DIR = os.path.join(ROOT, ".jax_cache")
+
 #: CI smoke mode: tiny instances, tiny machine (see module docstring)
 SMOKE = os.environ.get("BENCH_SMOKE", "") == "1"
 
@@ -55,8 +63,20 @@ def emit(rows, name):
 #: must never overwrite the committed numbers)
 BENCH_SWEEP_PATH = (
     os.path.join(OUT_DIR, "BENCH_sweep_smoke.json") if SMOKE else
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 "BENCH_sweep.json"))
+    os.path.join(ROOT, "BENCH_sweep.json"))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself), else
+    :data:`JAX_CACHE_DIR`."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = JAX_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def merge_bench_sweep(updates: dict) -> dict:

@@ -30,11 +30,6 @@ import os
 import sys
 import time
 
-# The simulator step is hundreds of small int ops; XLA:CPU's thunk runtime
-# adds per-op overhead that the legacy emitter avoids (~20% wall-clock on
-# the sweeps).  Must be set before jax initializes, so: before suite imports.
-os.environ.setdefault("XLA_FLAGS", "--xla_cpu_use_thunk_runtime=false")
-
 # RuntimeSpec axis values, spelled out here so --list/--spec answer without
 # importing jax (keep in sync with repro.core.spec — test_spec asserts it)
 AXIS_VALUES = dict(
@@ -264,6 +259,8 @@ def main() -> None:
     if unknown:
         raise SystemExit(f"unknown suite(s): {sorted(unknown)}; "
                          f"available: {sorted(SUITES)} (see --list)")
+    from benchmarks.common import use_compile_cache
+    print(f"# compile cache: {use_compile_cache()}", flush=True)
     tracer = None
     if profile:
         # jax.profiler.trace wraps the whole selected run (viewable with

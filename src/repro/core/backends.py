@@ -9,12 +9,14 @@ the :class:`~repro.core.phases.StepOps` kernel set the phases run on:
   bitwise to the pre-decomposition results by ``tests/golden_modes.json``.
 * ``pallas``    — Pallas kernels for the hot queue traffic (the per-pair
   SPSC push / pop-scan of :mod:`repro.core.xqueue` and the one-hot counter
-  bumps), following the :mod:`repro.kernels.ops` idiom: compiled on TPU,
-  ``interpret=True`` elsewhere, so the same backend runs in CI on CPU.
+  bumps): compiled where the step is lowered for a TPU, interpreted where
+  it is lowered for the CPU, so the same backend runs in CI.
 * ``pallas_fused`` — the whole-step megakernel: the entire composed
   pipeline (adopt → spawn → dequeue → thief → victim → exec) as *one*
   Pallas launch per scheduling point (:mod:`repro.kernels.sched_step`),
-  running the reference math cores inside the kernel body.
+  running the reference math cores inside the kernel body.  It runs
+  interpreted on the CPU only: Mosaic does not lower its body, so a TPU
+  compile raises the compiler's error.
 
 Backends are **bitwise identical by contract** — same makespans, counters,
 step counts on every lattice point and executor (tests/test_backends.py
@@ -90,7 +92,8 @@ class ReferenceBackend(StepBackend):
 
 
 class PallasBackend(StepBackend):
-    """Pallas kernels for the hot queue phases (interpret mode off-TPU).
+    """Pallas kernels for the hot queue phases (compiled for a TPU,
+    interpreted on the CPU).
 
     The kernel set is imported lazily so merely listing backends never pulls
     in pallas machinery; see :mod:`repro.kernels.sched_queue`.

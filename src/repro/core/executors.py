@@ -51,7 +51,6 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -144,9 +143,9 @@ def _init_batch_sharded(cfg: SimConfig, gq_cap: int, n_dev: int, gb,
     buffers in place (a single-device state would defeat the donation —
     mismatched shardings can't alias)."""
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("b",))
-    return shard_map(functools.partial(_init_body, cfg, gq_cap), mesh=mesh,
-                     in_specs=(P("b"), P("b")), out_specs=P("b"),
-                     check_rep=False)(gb, cb)
+    return jax.shard_map(functools.partial(_init_body, cfg, gq_cap),
+                         mesh=mesh, in_specs=(P("b"), P("b")),
+                         out_specs=P("b"), check_vma=False)(gb, cb)
 
 
 def _batch_body(cfg: SimConfig, gq_cap: int, gb, cb: SweepCase, st0):
@@ -213,10 +212,9 @@ def _run_batch_sharded(cfg: SimConfig, gq_cap: int, n_dev: int, gb,
     all finish (or stall) stops stepping early."""
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("b",))
     body = functools.partial(_batch_body, cfg, gq_cap)
-    # check_rep=False: jax 0.4.x has no replication rule for while_loop;
-    # nothing here is replicated anyway (every in/out is batch-sharded)
-    return shard_map(body, mesh=mesh, in_specs=(P("b"), P("b"), P("b")),
-                     out_specs=P("b"), check_rep=False)(gb, cb, st0)
+    # check_vma=False: every in/out is batch-sharded, nothing is replicated
+    return jax.shard_map(body, mesh=mesh, in_specs=(P("b"), P("b"), P("b")),
+                         out_specs=P("b"), check_vma=False)(gb, cb, st0)
 
 
 def _stack_chunk(ctx: ExecContext, specs_chunk: Sequence[CaseSpec],

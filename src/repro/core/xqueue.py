@@ -125,11 +125,9 @@ def pop_compute(buf: jax.Array, ts: jax.Array, head: jax.Array,
                 tail: jax.Array, rot: jax.Array, mask: jax.Array, n_active):
     """The pop scan as pure array math (the shared math core).
 
-    Operates on the raw XQ arrays so both the reference jnp path
-    (:func:`pop_first`) and the Pallas kernel
-    (:mod:`repro.kernels.sched_queue`, which runs this same math
-    VMEM-resident inside one fused kernel) execute the identical int
-    arithmetic — backend bitwise equality by construction.
+    Operates on the raw XQ arrays.  The Pallas pop kernel
+    (:mod:`repro.kernels.sched_queue`) is this math with its gathers written
+    as one-hot sums, which Mosaic lowers; tests assert the two bitwise.
 
     Returns ``(head', task, ts, src, found, checked)``.
     """

@@ -28,12 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params(dimension_semantics):
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=dimension_semantics) if cls else None
-
-
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             block_q: int, block_k: int, nk: int, causal: bool, window: int,
             softcap: Optional[float], scale: float):
@@ -118,7 +112,8 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=None if interpret else _compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -25,12 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params(dimension_semantics):
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=dimension_semantics) if cls else None
-
-
 def _kernel(x_ref, e_ref, p_ref, o_ref, *, block_t: int, k: int):
     e = pl.program_id(0)
     j = pl.program_id(1)
@@ -72,7 +66,7 @@ def moe_dispatch_pallas(x, expert, pos, *, n_experts: int, capacity: int,
         ],
         out_specs=pl.BlockSpec((1, capacity, D), lambda e, j: (e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_experts, capacity, D), x.dtype),
-        compiler_params=None if interpret else _compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, expert, pos)

@@ -21,12 +21,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params(dimension_semantics):
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    return cls(dimension_semantics=dimension_semantics) if cls else None
-
-
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sN_ref, s_ref,
             *, block_t: int, nt: int):
     j = pl.program_id(2)
@@ -84,8 +78,8 @@ def rwkv6_pallas(r, k, v, w, u, state, *, block_t: int = 128,
             jax.ShapeDtypeStruct((B, H, Dh, Dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Dh, Dh), jnp.float32)],
-        compiler_params=None if interpret else _compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, state)
     return out, s_new

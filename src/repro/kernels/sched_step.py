@@ -31,9 +31,12 @@ Fusion contract (the ``pallas_fused`` backend of
   ``run_gate``-driven ``while_loop``, and the host-side barrier episode is
   accounted after the run as always.
 
-Following the :mod:`repro.kernels.ops` idiom: compiled on TPU backends,
-``interpret=True`` everywhere else, so CI drives the exact kernel code on
-CPU.  The call is grid-free (the per-simulation working set lives in one
+The call is interpreted where it is lowered for anything but a TPU, so CI
+drives the exact kernel code on CPU.  Lowered for a TPU it goes to Mosaic,
+which does not lower this body yet (the reference pipeline's scatter-add
+counter bump is the first primitive it refuses; ROADMAP Speed 2): a TPU
+compile raises that error and never falls back to the interpreter.  The
+call is grid-free (the per-simulation working set lives in one
 block) and vmap/shard_map-safe — the graph and case leaves enter as kernel
 operands, so the sweep executors batch the megakernel like any other step.
 """
@@ -45,16 +48,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
-from jax.experimental import pallas as pl
 
 from repro.core import phases
 from repro.core.phases import REFERENCE_OPS
 from repro.core.state import GraphArrays, SimState, SweepCase  # noqa: F401
 from repro.core.costs import CostModel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.sched_queue import platform_pallas_call
 
 
 def _enc(x: jax.Array, batch: bool = False) -> jax.Array:
@@ -118,11 +117,10 @@ def _pallas_step(leaves, treedef, n_st: int, costs: CostModel,
     kernel = functools.partial(
         _step_kernel, treedef=treedef, in_avals=avals,
         st_avals=st_avals, costs=costs, max_steps=max_steps, batch=batch)
-    outs = pl.pallas_call(
+    outs = platform_pallas_call(
         kernel,
         out_shape=tuple(_enc_sds(a, batch) for a in st_avals),
         input_output_aliases={i: i for i in range(n_st)},
-        interpret=_interpret(),
     )(*[_enc(x, batch) for x in leaves])
     return [_dec(o, a, batch) for o, a in zip(outs, st_avals)]
 

@@ -108,7 +108,6 @@ def _route_dispatch_shard_map(xt, logits, cfg: ModelConfig, cap, groups,
     device-local — the jit global-view formulation replicates the (T*k)-sized
     argsorts on every device and lowers the sharded scatter to all-gathers.
     Only the expert dimension leaves the shard afterwards (EP)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -121,9 +120,7 @@ def _route_dispatch_shard_map(xt, logits, cfg: ModelConfig, cap, groups,
     def local_fn(xt_l, logits_l):
         shard = jnp.int32(0)
         for ax in dp:
-            # psum of the literal 1 folds to the static mesh axis size
-            # (jax 0.4.x has no public jax.lax.axis_size)
-            shard = shard * jax.lax.psum(1, ax) + jax.lax.axis_index(ax)
+            shard = shard * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         key = jax.random.fold_in(rng, shard)
         r = balance.route(logits_l, m.top_k, cap, groups,
                           strategy=m.strategy, p_local=m.p_local, key=key)
@@ -140,9 +137,9 @@ def _route_dispatch_shard_map(xt, logits, cfg: ModelConfig, cap, groups,
                  {k: P() for k in ("ntasks_static", "ntasks_stolen_local",
                                    "ntasks_stolen_remote", "ntasks_dropped",
                                    "max_load")})
-    buf, ve, pos, weight, probs, stats = shard_map(
+    buf, ve, pos, weight, probs, stats = jax.shard_map(
         local_fn, mesh=mesh, in_specs=specs_in, out_specs=specs_out,
-        check_rep=False)(xt, logits)
+        check_vma=False)(xt, logits)
     # global views: (G,E,C,D) buffers; (T,k) routing tables; (T,E) probs
     k = m.top_k
     return (buf, ve.reshape(T, k), pos.reshape(T, k),
@@ -150,7 +147,6 @@ def _route_dispatch_shard_map(xt, logits, cfg: ModelConfig, cap, groups,
 
 
 def _combine_shard_map(y, ve, pos, weight, cfg: ModelConfig, T):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -172,10 +168,10 @@ def _combine_shard_map(y, ve, pos, weight, cfg: ModelConfig, T):
     def regroup(a):      # (T, k) -> (G, T/G, k): shard_map splits dim 0
         return a.reshape(G, T // G, k)
 
-    out = shard_map(
+    out = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, None, None),
                   P(dp, None, None), P(dp, None, None)),
-        out_specs=P(dp, None, None), check_rep=False)(
+        out_specs=P(dp, None, None), check_vma=False)(
         y, regroup(ve), regroup(pos), regroup(weight))
     return out.reshape(T, -1)

@@ -35,9 +35,7 @@ def tree_allreduce(x, *, intra_axes, inter_axis):
     intra = intra_axes if isinstance(intra_axes, (tuple, list)) else (intra_axes,)
     size = 1
     for ax in intra:
-        # psum of the literal 1 folds to the static mesh axis size
-        # (jax 0.4.x has no public jax.lax.axis_size)
-        size *= jax.lax.psum(1, ax)
+        size *= jax.lax.axis_size(ax)
     flat = x.reshape(-1)
     n = flat.shape[0]
     if n % size != 0:  # tiny tensors: flat reduce is cheaper anyway

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 from functools import partial
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.runtime.collectives import tree_allreduce, flat_psum_grads, hierarchical_psum_grads
 from repro.launch import hlo_analysis as ha
 
@@ -28,16 +27,16 @@ def tree(v):
     return tree_allreduce(v, intra_axes=("data",), inter_axis="pod")
 
 spec = P("pod", "data", "model", None)
-run_flat = jax.jit(shard_map(flat, mesh=mesh, in_specs=spec, out_specs=spec))
-run_tree = jax.jit(shard_map(tree, mesh=mesh, in_specs=spec, out_specs=spec))
+run_flat = jax.jit(jax.shard_map(flat, mesh=mesh, in_specs=spec, out_specs=spec))
+run_tree = jax.jit(jax.shard_map(tree, mesh=mesh, in_specs=spec, out_specs=spec))
 a = run_flat(x); b = run_tree(x)
 np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 # non-divisible fallback path
 y = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 2, 3))
 spec3 = P("pod", "data", "model", None)
-a = jax.jit(shard_map(flat, mesh=mesh, in_specs=spec3, out_specs=spec3))(y)
-b = jax.jit(shard_map(tree, mesh=mesh, in_specs=spec3, out_specs=spec3))(y)
+a = jax.jit(jax.shard_map(flat, mesh=mesh, in_specs=spec3, out_specs=spec3))(y)
+b = jax.jit(jax.shard_map(tree, mesh=mesh, in_specs=spec3, out_specs=spec3))(y)
 np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 print("PASS")
