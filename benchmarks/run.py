@@ -274,7 +274,6 @@ def main() -> None:
         tracer = contextlib.ExitStack()
         tracer.enter_context(jax.profiler.trace(trace_dir))
         executors_mod.reset_engine_stats()
-        profile_t0 = time.time()
     failures = []
     ran = 0
     for name, info in SUITES.items():
@@ -294,14 +293,14 @@ def main() -> None:
             print(f"# {name} FAILED: {e!r}", flush=True)
     if tracer is not None:
         tracer.close()
-        wall = time.time() - profile_t0
         stats = dict(executors_mod.ENGINE_STATS)
-        per_step = (wall / stats["sim_steps"] * 1e6
-                    if stats["sim_steps"] else float("nan"))
-        print(f"# profile: trace under {trace_dir}; "
-              f"{stats['dispatches']} dispatches over {stats['chunks']} "
-              f"chunks, {stats['sim_steps']} simulated steps, "
-              f"{per_step:.1f} us/step wall", flush=True)
+        share = (stats["sim_steps"] / stats["lane_slots"]
+                 if stats["lane_slots"] else float("nan"))
+        print(f"# profile: trace under {trace_dir}, the engine's host work "
+              f"in its repro.* spans; {stats['dispatches']} dispatches "
+              f"over {stats['chunks']} chunks, {stats['sim_steps']} "
+              f"simulated steps in {stats['loop_iterations']} loop "
+              f"iterations, lane-step share {share:.4f}", flush=True)
     if failures:
         print("# FAILURES:", failures)
         raise SystemExit(1)

@@ -67,8 +67,14 @@ from repro.core.taskgraph import TaskGraph
 #: them): ``dispatches`` counts device dispatches (one per serial case /
 #: one per batched chunk), ``chunks`` the chunks submitted, ``sim_steps``
 #: the simulated scheduling points executed (accumulated by the sweep
-#: layer).  Reset with :func:`reset_engine_stats`.
-ENGINE_STATS = {"dispatches": 0, "chunks": 0, "sim_steps": 0}
+#: layer).  ``loop_iterations`` counts the device loops' iterations: each
+#: device loops over its slice of a chunk's padded lanes until the slice's
+#: slowest lane stops, so a slice runs its largest step count.
+#: ``lane_slots`` is Σ padded lanes × iterations over the slices, so
+#: ``sim_steps / lane_slots`` is the share of lane-steps that advance a
+#: real case.  Reset with :func:`reset_engine_stats`.
+ENGINE_STATS = {"dispatches": 0, "chunks": 0, "sim_steps": 0,
+                "loop_iterations": 0, "lane_slots": 0}
 
 
 def reset_engine_stats() -> dict:
@@ -76,6 +82,23 @@ def reset_engine_stats() -> dict:
     for k in ENGINE_STATS:
         ENGINE_STATS[k] = 0
     return ENGINE_STATS
+
+
+def _count_loops(step_i: np.ndarray, n_slices: int) -> None:
+    """Add one chunk's loops to ``ENGINE_STATS``: ``step_i`` holds every
+    padded lane's steps, in ``n_slices`` equal contiguous slices that each
+    loop until their slowest lane stops."""
+    iters = int(step_i.reshape(n_slices, -1).max(axis=1).sum())
+    ENGINE_STATS["loop_iterations"] += iters
+    ENGINE_STATS["lane_slots"] += iters * (step_i.size // n_slices)
+
+
+def span(name: str, **args):
+    """A host span ``repro.<name>`` on the profiler's clock.  A TraceMe:
+    with no profiler running it costs a check.  Spans are per call or per
+    chunk, never per case or per loop iteration; ``args`` identify the
+    call and chunk they belong to."""
+    return jax.profiler.TraceAnnotation("repro." + name, **args)
 
 
 class ChunkRaw(NamedTuple):
@@ -104,6 +127,7 @@ class ExecContext:
     graphs: Sequence[TaskGraph]
     garr: Sequence[GraphArrays]      # padded to the plan's t_pad
     release_len: int = 1
+    call: int = 0                    # the run_cases call (names its spans)
 
     def case_for(self, s: CaseSpec) -> SweepCase:
         if s.arrivals is None and self.release_len == 1:
@@ -244,6 +268,15 @@ class Executor(abc.ABC):
 
     name: str = "?"
 
+    def padded_size(self, chunk: ChunkPlan) -> int:
+        """Lanes the chunk's device loops run, padding included."""
+        return chunk.n_real
+
+    def chunk_args(self, ctx: ExecContext, chunk: ChunkPlan) -> dict:
+        """The identifiers every span of one chunk carries."""
+        return dict(call=ctx.call, chunk=chunk.index, lanes=chunk.n_real,
+                    padded=self.padded_size(chunk))
+
     @abc.abstractmethod
     def submit(self, ctx: ExecContext, specs: Sequence[CaseSpec],
                chunk: ChunkPlan):
@@ -264,18 +297,25 @@ class SerialExecutor(Executor):
     name = "serial"
 
     def submit(self, ctx, specs, chunk):
-        states = []
-        for i in chunk.indices:
-            s = specs[i]
-            garr, case = ctx.garr[s.graph], ctx.case_for(s)
-            st0 = _init_cached(ctx.cfg, ctx.gq_cap, garr, case)
-            states.append(
-                _run_cached(ctx.cfg, ctx.gq_cap, garr, case, st0))
-            ENGINE_STATS["dispatches"] += 1
+        args = self.chunk_args(ctx, chunk)
+        with span("submit", **args):
+            with span("stack", **args):
+                cases = [(ctx.garr[specs[i].graph], ctx.case_for(specs[i]))
+                         for i in chunk.indices]
+            # each case's init is dispatched right before its run, so the
+            # chunk's initial states are not all made up front: serial has
+            # no init span
+            with span("dispatch", **args):
+                states = [_run_cached(ctx.cfg, ctx.gq_cap, garr, case,
+                                      _init_cached(ctx.cfg, ctx.gq_cap, garr,
+                                                   case))
+                          for garr, case in cases]
+        ENGINE_STATS["dispatches"] += len(states)
         ENGINE_STATS["chunks"] += 1
-        return states
+        return states, args
 
-    def collect(self, states):
+    def collect(self, pending):
+        states, args = pending
         n = len(states)
         W = states[0].clock.shape[0]
         T = states[0].done_ns.shape[0]
@@ -285,14 +325,18 @@ class SerialExecutor(Executor):
         overflow = np.zeros(n, bool)
         step_i = np.zeros(n, np.int64)
         done_ns = np.zeros((n, T), np.int64)
-        for j, st in enumerate(states):
-            st = jax.block_until_ready(st)
-            clock[j] = np.asarray(st.clock)
-            ctr[j] = np.asarray(st.ctr)
-            n_done[j] = int(st.n_done)
-            overflow[j] = bool(st.overflow)
-            step_i[j] = int(st.step_i)
-            done_ns[j] = np.asarray(st.done_ns)
+        with span("collect", **args):
+            with span("wait", **args):
+                states = jax.block_until_ready(states)
+            with span("fetch", **args):
+                for j, st in enumerate(states):
+                    clock[j] = np.asarray(st.clock)
+                    ctr[j] = np.asarray(st.ctr)
+                    n_done[j] = int(st.n_done)
+                    overflow[j] = bool(st.overflow)
+                    step_i[j] = int(st.step_i)
+                    done_ns[j] = np.asarray(st.done_ns)
+        _count_loops(step_i, n)          # one one-lane loop per case
         return ChunkRaw(clock, ctr, n_done, overflow, step_i, done_ns)
 
 
@@ -302,24 +346,44 @@ class VmapExecutor(Executor):
     def padded_size(self, chunk: ChunkPlan) -> int:
         return chunk.padded_size
 
+    def n_devices(self) -> int:
+        """Devices a chunk's lanes are split over, in contiguous slices."""
+        return 1
+
     def submit(self, ctx, specs, chunk):
-        gb, cb = _stack_chunk(ctx, [specs[i] for i in chunk.indices],
-                              self.padded_size(chunk))
+        args = self.chunk_args(ctx, chunk)
+        with span("submit", **args):
+            with span("stack", **args):
+                gb, cb = _stack_chunk(ctx, [specs[i] for i in chunk.indices],
+                                      args["padded"])
+            with span("init", **args):
+                st0 = self._init(ctx, gb, cb)
+            with span("dispatch", **args):
+                st = self._run(ctx, gb, cb, st0)
         ENGINE_STATS["dispatches"] += 1
         ENGINE_STATS["chunks"] += 1
-        return self._dispatch(ctx, gb, cb), chunk.n_real
+        return st, args
 
     def collect(self, pending):
-        st, n = pending
-        st = jax.block_until_ready(st)
-        return ChunkRaw(np.asarray(st.clock)[:n], np.asarray(st.ctr)[:n],
-                        np.asarray(st.n_done)[:n],
-                        np.asarray(st.overflow)[:n],
-                        np.asarray(st.step_i)[:n],
-                        np.asarray(st.done_ns)[:n])
+        st, args = pending
+        n = args["lanes"]
+        with span("collect", **args):
+            with span("wait", **args):
+                st = jax.block_until_ready(st)
+            with span("fetch", **args):
+                step_i = np.asarray(st.step_i)
+                raw = ChunkRaw(np.asarray(st.clock)[:n],
+                               np.asarray(st.ctr)[:n],
+                               np.asarray(st.n_done)[:n],
+                               np.asarray(st.overflow)[:n], step_i[:n],
+                               np.asarray(st.done_ns)[:n])
+        _count_loops(step_i, self.n_devices())
+        return raw
 
-    def _dispatch(self, ctx, gb, cb):
-        st0 = _init_batch(ctx.cfg, ctx.gq_cap, gb, cb)
+    def _init(self, ctx, gb, cb):
+        return _init_batch(ctx.cfg, ctx.gq_cap, gb, cb)
+
+    def _run(self, ctx, gb, cb, st0):
         return _run_batch(ctx.cfg, ctx.gq_cap, gb, cb, st0)
 
 
@@ -333,10 +397,16 @@ class ShardedExecutor(VmapExecutor):
         p = chunk.padded_size
         return -(-p // n_dev) * n_dev
 
-    def _dispatch(self, ctx, gb, cb):
-        n_dev = jax.device_count()
-        st0 = _init_batch_sharded(ctx.cfg, ctx.gq_cap, n_dev, gb, cb)
-        return _run_batch_sharded(ctx.cfg, ctx.gq_cap, n_dev, gb, cb, st0)
+    def n_devices(self) -> int:
+        return jax.device_count()
+
+    def _init(self, ctx, gb, cb):
+        return _init_batch_sharded(ctx.cfg, ctx.gq_cap, jax.device_count(),
+                                   gb, cb)
+
+    def _run(self, ctx, gb, cb, st0):
+        return _run_batch_sharded(ctx.cfg, ctx.gq_cap, jax.device_count(),
+                                  gb, cb, st0)
 
 
 EXECUTORS = {e.name: e for e in

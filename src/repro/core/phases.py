@@ -676,10 +676,12 @@ def run_gate(st: SimState, g: GraphArrays, max_steps: int) -> jax.Array:
     same step under every executor — stalled lanes stay bitwise identical
     across serial, vmap, and sharded runs.
     """
-    has_work = (jnp.any(st.s_top > 0) | jnp.any(st.xq.tail > st.xq.head)
-                | (st.g_tail > st.g_head))
-    return ((st.n_done < g.n_tasks) & (st.step_i < max_steps)
-            & ~st.overflow & has_work)
+    with jax.named_scope("gate"):
+        has_work = (jnp.any(st.s_top > 0)
+                    | jnp.any(st.xq.tail > st.xq.head)
+                    | (st.g_tail > st.g_head))
+        return ((st.n_done < g.n_tasks) & (st.step_i < max_steps)
+                & ~st.overflow & has_work)
 
 
 def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
@@ -695,27 +697,40 @@ def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
     a simulation finishes or stalls, its step is a strict no-op, which is
     what lets the batched engine drive a plain ``while any(alive)`` loop
     over vmapped steps without per-element freeze machinery.
+
+    Each phase runs under a ``jax.named_scope`` of its short name
+    (``adopt`` … ``exec``), the cluster occupancy charge under
+    ``occupancy`` and :func:`run_gate` under ``gate``: HLO metadata only,
+    which a device trace reads as per-phase time.
     """
     running = run_gate(st, g, max_steps)
-    st = adopt_phase(st, running, case=case, costs=costs, ops=ops)
-    st = spawn_phase(st, running, g=g, case=case, costs=costs, ops=ops)
-    st, task, ts, found = dequeue_phase(st, running, g=g, case=case,
-                                        costs=costs, ops=ops)
-    st = thief_phase(st, found, running, case=case, costs=costs, ops=ops)
-    st = victim_phase(st, found, g=g, case=case, costs=costs, ops=ops)
-    st = exec_phase(st, task, ts, found, g=g, case=case, costs=costs,
-                    ops=ops)
+    with jax.named_scope("adopt"):
+        st = adopt_phase(st, running, case=case, costs=costs, ops=ops)
+    with jax.named_scope("spawn"):
+        st = spawn_phase(st, running, g=g, case=case, costs=costs, ops=ops)
+    with jax.named_scope("dequeue"):
+        st, task, ts, found = dequeue_phase(st, running, g=g, case=case,
+                                            costs=costs, ops=ops)
+    with jax.named_scope("thief"):
+        st = thief_phase(st, found, running, case=case, costs=costs,
+                         ops=ops)
+    with jax.named_scope("victim"):
+        st = victim_phase(st, found, g=g, case=case, costs=costs, ops=ops)
+    with jax.named_scope("exec"):
+        st = exec_phase(st, task, ts, found, g=g, case=case, costs=costs,
+                        ops=ops)
     # shared inter-node bottleneck (cluster tier): all cross-node bytes
     # moved this step contend for one uplink, so each sender additionally
     # waits out the *other* senders' occupancy (total-minus-own over the
     # bottleneck bandwidth).  The ledger stays identically zero off-cluster
     # — flat and single-node machines add 0 to every clock — and resets
     # each step, making the charge a per-step occupancy model.
-    nl = st.nlink_bytes
-    occ = jnp.where((nl > 0) & case.topo.cluster,
-                    (jnp.sum(nl) - nl) // case.topo.bneck_bw,
-                    0).astype(jnp.int32)
-    st = st._replace(clock=st.clock + occ,
-                     ctr=_bump(ops, st.ctr, "xnode_bytes", nl),
-                     nlink_bytes=jnp.zeros_like(nl))
+    with jax.named_scope("occupancy"):
+        nl = st.nlink_bytes
+        occ = jnp.where((nl > 0) & case.topo.cluster,
+                        (jnp.sum(nl) - nl) // case.topo.bneck_bw,
+                        0).astype(jnp.int32)
+        st = st._replace(clock=st.clock + occ,
+                         ctr=_bump(ops, st.ctr, "xnode_bytes", nl),
+                         nlink_bytes=jnp.zeros_like(nl))
     return st._replace(step_i=st.step_i + running.astype(jnp.int32))

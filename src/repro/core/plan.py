@@ -126,6 +126,7 @@ class ChunkPlan:
     indices: Tuple[int, ...]
     spec: RuntimeSpec
     hetero_dlb: bool    # >1 distinct DLB knob tuple under a DLB balancer
+    index: int = 0      # position in the plan's chunks (names its spans)
 
     @property
     def mode(self) -> str:
@@ -197,12 +198,12 @@ def build_plan(graphs: Sequence[TaskGraph], specs: Sequence[CaseSpec],
         else:
             groups.append([i])
     chunks = []
-    for idxs in groups:
+    for k, idxs in enumerate(groups):
         spec = specs[idxs[0]].spec
         hetero = (spec.balance in DLB_BALANCERS
                   and len({specs[i].knobs for i in idxs}) > 1)
         chunks.append(ChunkPlan(indices=tuple(idxs), spec=spec,
-                                hetero_dlb=hetero))
+                                hetero_dlb=hetero, index=k))
     plan = SweepPlan(n_cases=len(specs), w_pad=w_pad, t_pad=t_pad,
                      gq_cap=gq_cap, chunks=tuple(chunks))
     plan.validate()

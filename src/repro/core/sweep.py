@@ -41,6 +41,7 @@ engine under any executor, a single-configuration engine run matches
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 import warnings
 from typing import Dict, List, Optional, Sequence
@@ -53,13 +54,17 @@ from repro.core import barrier as barrier_mod
 from repro.core import cache as cache_mod
 from repro.core import executors as executors_mod
 from repro.core import topology as topology_mod
-from repro.core.executors import STRATEGIES, ExecContext, select_executor
+from repro.core.executors import (STRATEGIES, ExecContext, select_executor,
+                                  span)
 from repro.core.plan import CaseSpec, build_plan
 from repro.core.scheduler import CTR_NAMES, SimConfig, graph_arrays
 from repro.core.spec import AXES, RuntimeSpec, spec_product
 from repro.core.taskgraph import TaskGraph
 
 __all__ = ["CaseSpec", "SweepResult", "run_cases", "run_grid"]
+
+#: numbers the process's ``run_cases`` calls, for their spans' ``call``
+_CALLS = itertools.count(1)
 
 
 @dataclasses.dataclass
@@ -174,6 +179,19 @@ def run_cases(graphs: Sequence[TaskGraph] | TaskGraph,
     cfg = dataclasses.replace(cfg, backend=backends_mod.resolve_name(
         backend if backend is not None else cfg.backend))
 
+    call = next(_CALLS)
+    with span("run_cases", call=call, rows=len(specs)) as root:
+        result, n_chunks = _run_cases(graphs, specs, cfg, chunk_size,
+                                      strategy, cache, pipeline, call)
+        root.set_metadata(chunks=n_chunks)
+    return result
+
+
+def _run_cases(graphs: List[TaskGraph], specs: List[CaseSpec],
+               cfg: SimConfig, chunk_size: int, strategy: str, cache,
+               pipeline: bool, call: int) -> tuple[SweepResult, int]:
+    """The body of :func:`run_cases`, under its root span; also returns the
+    number of chunks it ran."""
     t0 = time.perf_counter()
     B = len(specs)
     clock_max = np.zeros(B, np.int64)
@@ -218,16 +236,21 @@ def run_cases(graphs: Sequence[TaskGraph] | TaskGraph,
             # SLO arrays just stay NaN for them
             fill_slo(i, rec.get("slo"))
 
+    n_chunks = 0
     if miss:
         miss_specs = [specs[i] for i in miss]
-        plan = build_plan(graphs, miss_specs, chunk_size=chunk_size)
-        run_cfg = dataclasses.replace(cfg, n_workers=plan.w_pad)
-        ctx = ExecContext(
-            cfg=run_cfg, gq_cap=plan.gq_cap, graphs=graphs,
-            garr=[graph_arrays(g, plan.t_pad) for g in graphs],
-            release_len=(plan.t_pad
-                         if any(s.arrivals is not None for s in miss_specs)
-                         else 1))
+        with span("plan", call=call):
+            plan = build_plan(graphs, miss_specs, chunk_size=chunk_size)
+            run_cfg = dataclasses.replace(cfg, n_workers=plan.w_pad)
+            ctx = ExecContext(
+                cfg=run_cfg, gq_cap=plan.gq_cap, graphs=graphs,
+                garr=[graph_arrays(g, plan.t_pad) for g in graphs],
+                release_len=(plan.t_pad
+                             if any(s.arrivals is not None for s in miss_specs)
+                             else 1),
+                call=call)
+        n_chunks = len(plan.chunks)
+
         def postprocess(chunk, raw) -> None:
             executors_mod.ENGINE_STATS["sim_steps"] += int(raw.step_i.sum())
             for j, mi in enumerate(chunk.indices):
@@ -262,44 +285,50 @@ def run_cases(graphs: Sequence[TaskGraph] | TaskGraph,
         # so the host's next-chunk work and post-processing overlap the
         # device's current-chunk execution.  Dispatch reordering only —
         # per-case results are bitwise identical either way.
+        def collect(ex, handle, chunk) -> None:
+            raw = ex.collect(handle)
+            with span("postprocess", **ex.chunk_args(ctx, chunk)):
+                postprocess(chunk, raw)
+
         pending = None  # (executor, handle, chunk) in flight
         for chunk in plan.chunks:
             ex = select_executor(strategy, chunk)
             handle = ex.submit(ctx, miss_specs, chunk)
             if not pipeline:
-                postprocess(chunk, ex.collect(handle))
+                collect(ex, handle, chunk)
                 continue
             if pending is not None:
-                postprocess(pending[2], pending[0].collect(pending[1]))
+                collect(*pending)
             pending = (ex, handle, chunk)
         if pending is not None:
-            postprocess(pending[2], pending[0].collect(pending[1]))
+            collect(*pending)
 
-    # barrier episode per case (host-side: the barrier axis, W, and the
-    # machine topology are known per spec, matching run_schedule's
-    # accounting bit-for-bit; a non-flat topology lays the tree barrier
-    # out along the socket hierarchy — see barrier.tree_episode_topo)
-    ep_t = np.zeros(B, np.int64)
-    ep_a = np.zeros(B, np.int64)
-    for i, s in enumerate(specs):
-        ep = barrier_mod.episode_for(s.spec.barrier, s.n_workers, cfg.costs,
-                                     s.topology)
-        ep_t[i] = int(ep.time_ns)
-        ep_a[i] = int(ep.atomic_ops)
+    with span("finish", call=call):
+        # barrier episode per case (host-side: the barrier axis, W, and the
+        # machine topology are known per spec, matching run_schedule's
+        # accounting bit-for-bit; a non-flat topology lays the tree barrier
+        # out along the socket hierarchy — see barrier.tree_episode_topo)
+        ep_t = np.zeros(B, np.int64)
+        ep_a = np.zeros(B, np.int64)
+        for i, s in enumerate(specs):
+            ep = barrier_mod.episode_for(s.spec.barrier, s.n_workers, cfg.costs,
+                                         s.topology)
+            ep_t[i] = int(ep.time_ns)
+            ep_a[i] = int(ep.atomic_ops)
 
-    time_ns = clock_max + ep_t
-    counters = {n: ctr_sum[:, i].copy() for i, n in enumerate(CTR_NAMES)}
-    counters["atomic_ops"] = counters["atomic_ops"] + ep_a
-    completed = np.array(
-        [n_done[i] == graphs[s.graph].n_tasks and not overflow[i]
-         for i, s in enumerate(specs)])
+        time_ns = clock_max + ep_t
+        counters = {n: ctr_sum[:, i].copy() for i, n in enumerate(CTR_NAMES)}
+        counters["atomic_ops"] = counters["atomic_ops"] + ep_a
+        completed = np.array(
+            [n_done[i] == graphs[s.graph].n_tasks and not overflow[i]
+             for i, s in enumerate(specs)])
     return SweepResult(
         specs=specs, graph_names=[g.name for g in graphs],
         time_ns=time_ns, counters=counters, completed=completed,
         steps=step_i, wall_s=time.perf_counter() - t0, cache_hits=hits,
         p50_ns=slo_arr["p50_ns"], p90_ns=slo_arr["p90_ns"],
         p99_ns=slo_arr["p99_ns"],
-        throughput=slo_arr["throughput_tasks_per_s"])
+        throughput=slo_arr["throughput_tasks_per_s"]), n_chunks
 
 
 def run_grid(graphs: Sequence[TaskGraph] | TaskGraph,
