@@ -35,7 +35,7 @@ from __future__ import annotations
 import abc
 import os
 
-from repro.core import phases
+from repro.core import dlb, phases
 from repro.core.costs import CostModel
 from repro.core.phases import REFERENCE_OPS, StepOps
 from repro.core.state import GraphArrays, SweepCase
@@ -55,14 +55,17 @@ class StepBackend(abc.ABC):
         """The kernel set the phase pipeline runs on."""
 
     def build_step(self, W: int, S: int, costs: CostModel, g: GraphArrays,
-                   case: SweepCase, max_steps: int):
+                   case: SweepCase, max_steps: int,
+                   tables: dlb.VictimTables):
         """Compose the phase pipeline into ``step(st) -> st``.
 
         ``W``/``S``/``max_steps`` are static; everything
         configuration-dependent lives in the traced ``case``, and all
         spec-axis branching inside the phases is mask arithmetic — no
         Python control flow — so the returned ``step`` vmaps over a batch
-        of cases.
+        of cases.  ``tables`` is the case's
+        :func:`~repro.core.dlb.victim_tables`, which the caller builds once
+        per case outside its step loop.
 
         The composition itself is :func:`repro.core.phases.step_pipeline`
         (one definition, every backend): each phase is gated on the shared
@@ -76,8 +79,9 @@ class StepBackend(abc.ABC):
         ops = self.step_ops()
 
         def step(st):
-            return phases.step_pipeline(st, g=g, case=case, costs=costs,
-                                        ops=ops, max_steps=max_steps)
+            return phases.step_pipeline(st, g=g, case=case, tables=tables,
+                                        costs=costs, ops=ops,
+                                        max_steps=max_steps)
 
         return step
 
@@ -128,10 +132,11 @@ class PallasFusedBackend(StepBackend):
         return REFERENCE_OPS
 
     def build_step(self, W: int, S: int, costs: CostModel, g: GraphArrays,
-                   case: SweepCase, max_steps: int):
+                   case: SweepCase, max_steps: int,
+                   tables: dlb.VictimTables):
         del W, S
         from repro.kernels import sched_step
-        return sched_step.build_fused_step(costs, g, case, max_steps)
+        return sched_step.build_fused_step(costs, g, case, tables, max_steps)
 
 
 BACKENDS = {b.name: b for b in (ReferenceBackend(), PallasBackend(),

@@ -51,8 +51,10 @@ def remote_weight_table(me: jax.Array, n_workers, zone_size, topo,
     distance* — the nearest remote domain's workers carry weight
     ``1 + (d_max - d_near)``, the farthest carry ``1`` (integer weights off
     ``topo.dist``, so the draw→victim map stays exact).  Depends only on
-    ``me``/``zone_size``/``topo``, never on the PRNG draw, so callers
-    (``phases.thief_phase``) hoist it out of the victim-retry loop.
+    ``me``/``n_workers``/``zone_size``/``topo``, never on the PRNG draw or
+    the simulation state, so the step never builds it: :func:`victim_tables`
+    builds a case's three tables once, before the device loop, and
+    ``phases.thief_phase`` reads them at every scheduling point.
 
     ``restrict`` narrows the candidate set for the cluster tier's
     two-level choice: ``"node_local"`` keeps only remote-socket candidates
@@ -78,6 +80,35 @@ def remote_weight_table(me: jax.Array, n_workers, zone_size, topo,
     wgt = jnp.where(remote, dmax - d + 1, 0)                   # (W, W)
     cum = jnp.cumsum(wgt, axis=1)
     return cum, cum[:, -1]
+
+
+class VictimTables(NamedTuple):
+    """One case's victim-weight tables, each a :func:`remote_weight_table`
+    ``(cum (W, W), total (W,))`` pair: the unrestricted remote choice and
+    the cluster tier's ``node_local`` / ``node_remote`` split."""
+    remote: Tuple[jax.Array, jax.Array]
+    node_local: Tuple[jax.Array, jax.Array]
+    node_remote: Tuple[jax.Array, jax.Array]
+
+
+def victim_tables(W: int, case) -> VictimTables:
+    """The thief's victim-weight tables of one
+    :class:`~repro.core.state.SweepCase` at padded width ``W``.
+
+    They depend only on ``case.n_workers``/``zone_size``/``topo``, which a
+    run never changes, so every driver of the step
+    (``executors._batch_body`` under ``vmap``, ``scheduler._run_jit``)
+    builds them once, inside its jitted program and before its
+    ``while_loop``, and hands them to the step as a loop-invariant operand.
+    """
+    me = jnp.arange(W, dtype=jnp.int32)
+    n_w, zsz, topo = case.n_workers, case.zone_size, case.topo
+    return VictimTables(
+        remote=remote_weight_table(me, n_w, zsz, topo),
+        node_local=remote_weight_table(me, n_w, zsz, topo,
+                                       restrict="node_local"),
+        node_remote=remote_weight_table(me, n_w, zsz, topo,
+                                        restrict="node_remote"))
 
 
 def _remote_weighted(draw: jax.Array, cum: jax.Array, total: jax.Array

@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import arrivals as arrivals_mod
 from repro.core import backends as backends_mod
+from repro.core import dlb
 from repro.core import phases as phases_mod
 from repro.core.plan import CaseSpec, ChunkPlan
 from repro.core.scheduler import (NC, GraphArrays, SimConfig, SweepCase,
@@ -187,14 +188,17 @@ def _batch_body(cfg: SimConfig, gq_cap: int, gb, cb: SweepCase, st0):
     as soon as every lane is finished **or stalled**, instead of dragging
     a deadlocked lane to the padded max-step horizon.  Rows stay bitwise
     identical to the serial executor's because the gate freezes each lane's
-    ``step_i``/clock at the same step everywhere.  Returns only the arrays
-    the host needs (clock, counters, termination info)."""
+    ``step_i``/clock at the same step everywhere.  Each lane's victim-weight
+    tables (:func:`~repro.core.dlb.victim_tables`) are built once, before
+    the loop, and enter every step as a loop-invariant operand.  Returns
+    only the arrays the host needs (clock, counters, termination info)."""
 
     backend = backends_mod.get_backend(cfg.backend)
+    tb = jax.vmap(functools.partial(dlb.victim_tables, cfg.n_workers))(cb)
 
-    def step_one(g, case, st):
+    def step_one(g, case, tables, st):
         return backend.build_step(cfg.n_workers, cfg.stack_cap, cfg.costs,
-                                  g, case, cfg.max_steps)(st)
+                                  g, case, cfg.max_steps, tables)(st)
 
     def gate_one(g, st):
         return phases_mod.run_gate(st, g, cfg.max_steps)
@@ -206,7 +210,7 @@ def _batch_body(cfg: SimConfig, gq_cap: int, gb, cb: SweepCase, st0):
         return jnp.any(carry[0])
 
     def body(carry):
-        st = step_b(gb, cb, carry[1])
+        st = step_b(gb, cb, tb, carry[1])
         return gate_b(gb, st), st
 
     # the *full* final state is returned (not just the host-visible

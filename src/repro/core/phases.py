@@ -463,10 +463,12 @@ def dequeue_phase(st: SimState, running, *, g: GraphArrays, case: SweepCase,
 
 # ---------------- phase B2: thief protocol ----------------
 def thief_phase(st: SimState, found, running, *, case: SweepCase,
-                costs: CostModel, ops: StepOps = REFERENCE_OPS) -> SimState:
+                tables: dlb.VictimTables, costs: CostModel,
+                ops: StepOps = REFERENCE_OPS) -> SimState:
     """Idle workers that found nothing send steal requests to up to
     ``n_victim`` random victims (Alg. 1), on their first idle step and every
-    ``t_interval`` thereafter.
+    ``t_interval`` thereafter.  ``tables`` are the case's victim-weight
+    tables (:func:`repro.core.dlb.victim_tables`).
 
     Reads s_top/idle/rng/cells/clock; writes idle, rng, cells.req_round/
     req_tid (thief-owned), clock, ctr[req_sent].
@@ -492,14 +494,12 @@ def thief_phase(st: SimState, found, running, *, case: SweepCase,
     # the (batched) loop's per-iteration select overhead never touches
     # the big queue/stack/counter buffers.
     rounds = st.cells.round   # victim-owned; thieves only read it
-    # the (W, W) distance-weight tables are draw-independent: built once
-    # here, not per retry iteration (the node-split pair feeds the cluster
-    # tier's two-level victim choice; ignored off-cluster)
-    remote_tbl = dlb.remote_weight_table(me, n_w, zsz, case.topo)
-    node_tbls = (dlb.remote_weight_table(me, n_w, zsz, case.topo,
-                                         restrict="node_local"),
-                 dlb.remote_weight_table(me, n_w, zsz, case.topo,
-                                         restrict="node_remote"))
+    # the (W, W) distance-weight tables are built by the step's driver,
+    # once per case and outside its loop (dlb.victim_tables): XLA would
+    # not hoist their gather out of the device loop.  The node-split pair
+    # feeds the cluster tier's two-level victim choice (ignored
+    # off-cluster).
+    node_tbls = (tables.node_local, tables.node_remote)
 
     def cond(carry):
         v = carry[0]
@@ -509,7 +509,7 @@ def thief_phase(st: SimState, found, running, *, case: SweepCase,
         v, rng, req_round, req_tid, clock, n_sent, nl = carry
         sm = do_req & (v < params.n_victim)
         rng, victim = dlb.pick_victim(rng, me, n_w, zsz, params.p_local,
-                                      case.topo, remote_tbl=remote_tbl,
+                                      case.topo, remote_tbl=tables.remote,
                                       p_local_node=params.p_local_node,
                                       node_tbls=node_tbls)
         cells, sent = messaging.thief_send(
@@ -685,8 +685,8 @@ def run_gate(st: SimState, g: GraphArrays, max_steps: int) -> jax.Array:
 
 
 def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
-                  costs: CostModel, ops: StepOps = REFERENCE_OPS,
-                  max_steps: int) -> SimState:
+                  tables: dlb.VictimTables, costs: CostModel,
+                  ops: StepOps = REFERENCE_OPS, max_steps: int) -> SimState:
     """One scheduling point: the six phases composed in step order.
 
     This is the *whole* step body — backends differ only in the ``ops``
@@ -702,6 +702,10 @@ def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
     (``adopt`` … ``exec``), the cluster occupancy charge under
     ``occupancy`` and :func:`run_gate` under ``gate``: HLO metadata only,
     which a device trace reads as per-phase time.
+
+    ``tables`` are the case's victim-weight tables
+    (:func:`repro.core.dlb.victim_tables`), built by the step's driver
+    once per case, outside its loop.
     """
     running = run_gate(st, g, max_steps)
     with jax.named_scope("adopt"):
@@ -712,8 +716,8 @@ def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
         st, task, ts, found = dequeue_phase(st, running, g=g, case=case,
                                             costs=costs, ops=ops)
     with jax.named_scope("thief"):
-        st = thief_phase(st, found, running, case=case, costs=costs,
-                         ops=ops)
+        st = thief_phase(st, found, running, case=case, tables=tables,
+                         costs=costs, ops=ops)
     with jax.named_scope("victim"):
         st = victim_phase(st, found, g=g, case=case, costs=costs, ops=ops)
     with jax.named_scope("exec"):

@@ -71,6 +71,7 @@ import numpy as np
 from repro.core import arrivals as arrivals_mod
 from repro.core import backends as backends_mod
 from repro.core import barrier as barrier_mod
+from repro.core import dlb
 from repro.core import phases as phases_mod
 from repro.core import topology as topology_mod
 from repro.core.spec import MODE_SPECS, RuntimeSpec, resolve_spec
@@ -84,16 +85,9 @@ from repro.core.taskgraph import TaskGraph
 MODES = tuple(MODE_SPECS)
 MODE_ID = {m: i for i, m in enumerate(MODES)}
 
-# historical aliases for the pre-decomposition private API (the state and
-# step-builder moved to state.py / backends.py)
+# historical alias for the pre-decomposition private API (the state
+# moved to state.py)
 _init_state = init_state
-
-
-def _build_step(W: int, S: int, costs, g: GraphArrays, case: SweepCase,
-                max_steps: int, backend: str | None = "reference"):
-    """Legacy shim: the step body now composes in repro.core.backends."""
-    return backends_mod.get_backend(backend).build_step(
-        W, S, costs, g, case, max_steps)
 
 
 @dataclasses.dataclass
@@ -148,9 +142,12 @@ def _run_jit(cfg: SimConfig, gq_cap: int, g: GraphArrays,
     cond is the shared :func:`~repro.core.phases.run_gate` — identical to
     the step body's internal ``running`` gate, so completion, the step
     horizon, overflow, *and* a permanently stalled (workless) simulation
-    all stop the loop at the same step."""
+    all stop the loop at the same step.  The case's victim-weight tables
+    are built once here, before the loop."""
+    tables = dlb.victim_tables(cfg.n_workers, case)
     step = backends_mod.get_backend(cfg.backend).build_step(
-        cfg.n_workers, cfg.stack_cap, cfg.costs, g, case, cfg.max_steps)
+        cfg.n_workers, cfg.stack_cap, cfg.costs, g, case, cfg.max_steps,
+        tables)
 
     def cond(st):
         return phases_mod.run_gate(st, g, cfg.max_steps)

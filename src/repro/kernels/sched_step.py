@@ -19,11 +19,11 @@ Fusion contract (the ``pallas_fused`` backend of
   ``reference`` backend runs.  No arithmetic is re-derived; the only thing
   that changes is the launch granularity.
 * **Pytree marshalling at the boundary.**  Pallas refs carry arrays, not
-  pytrees, and want ≥1-d non-bool operands, so ``(st, g, case)`` flattens
-  to leaves with ``bool → int32`` and ``0-d → (1,)`` encodings applied at
-  the call boundary and undone first thing inside the kernel (and again on
-  the way out).  Dtypes otherwise survive untouched — int32 state, uint32
-  RNG lanes, float32 knobs.
+  pytrees, and want ≥1-d non-bool operands, so ``(st, g, case, tables)``
+  flattens to leaves with ``bool → int32`` and ``0-d → (1,)`` encodings
+  applied at the call boundary and undone first thing inside the kernel
+  (and again on the way out).  Dtypes otherwise survive untouched — int32
+  state, uint32 RNG lanes, float32 knobs.
 * **What still forces a phase boundary:** nothing *inside* a step — the
   internal ``while_loop``s (the execute-immediately rule, the thief retry,
   the one-shot join claim) trace into the kernel body as-is.  The step
@@ -37,8 +37,9 @@ which does not lower this body yet (the reference pipeline's scatter-add
 counter bump is the first primitive it refuses; ROADMAP Speed 2): a TPU
 compile raises that error and never falls back to the interpreter.  The
 call is grid-free (the per-simulation working set lives in one
-block) and vmap/shard_map-safe — the graph and case leaves enter as kernel
-operands, so the sweep executors batch the megakernel like any other step.
+block) and vmap/shard_map-safe — the graph, case and victim-table leaves
+enter as kernel operands, so the sweep executors batch the megakernel like
+any other step.
 """
 
 from __future__ import annotations
@@ -49,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
-from repro.core import phases
+from repro.core import dlb, phases
 from repro.core.phases import REFERENCE_OPS
 from repro.core.state import GraphArrays, SimState, SweepCase  # noqa: F401
 from repro.core.costs import CostModel
@@ -93,13 +94,14 @@ def _step_kernel(*refs, treedef, in_avals, st_avals, costs: CostModel,
     n_in = len(in_avals)
     in_refs, out_refs = refs[:n_in], refs[n_in:]
     leaves = [_dec(r[...], a, batch) for r, a in zip(in_refs, in_avals)]
-    st, g, case = jax.tree_util.tree_unflatten(treedef, leaves)
+    st, g, case, tables = jax.tree_util.tree_unflatten(treedef, leaves)
     run = functools.partial(phases.step_pipeline, costs=costs,
                             ops=REFERENCE_OPS, max_steps=max_steps)
     if batch:
-        st = jax.vmap(lambda s, gi, ci: run(s, g=gi, case=ci))(st, g, case)
+        st = jax.vmap(lambda s, gi, ci, ti: run(s, g=gi, case=ci, tables=ti)
+                      )(st, g, case, tables)
     else:
-        st = run(st, g=g, case=case)
+        st = run(st, g=g, case=case, tables=tables)
     out_leaves = jax.tree_util.tree_leaves(st)
     assert len(out_leaves) == len(st_avals) == len(out_refs)
     for r, leaf in zip(out_refs, out_leaves):
@@ -108,7 +110,8 @@ def _step_kernel(*refs, treedef, in_avals, st_avals, costs: CostModel,
 
 def _pallas_step(leaves, treedef, n_st: int, costs: CostModel,
                  max_steps: int, batch: bool):
-    """One ``pallas_call`` over the encoded leaves of ``(st, g, case)``;
+    """One ``pallas_call`` over the encoded leaves of
+    ``(st, g, case, tables)``;
     returns the decoded leaves of the next state.  State operands alias
     their outputs (the step is a state *update* — no second copy)."""
     leaves = [jnp.asarray(x) for x in leaves]
@@ -126,13 +129,14 @@ def _pallas_step(leaves, treedef, n_st: int, costs: CostModel,
 
 
 def build_fused_step(costs: CostModel, g: GraphArrays, case: SweepCase,
-                     max_steps: int):
+                     tables: dlb.VictimTables, max_steps: int):
     """Compose ``step(st) -> st`` as one fused Pallas launch.
 
     Mirrors ``StepBackend.build_step``: ``costs``/``max_steps`` are static
-    (baked into the kernel), ``g``/``case`` are traced pytrees entering as
-    kernel operands — so the returned ``step`` vmaps over a batch of
-    (graph, case, state) triples exactly like the unfused backends.
+    (baked into the kernel), ``g``/``case`` and the case's victim-weight
+    ``tables`` are traced pytrees entering as kernel operands — so the
+    returned ``step`` vmaps over a batch of (graph, case, tables, state)
+    tuples exactly like the unfused backends.
 
     Batching is a :func:`jax.custom_batching.custom_vmap` rule rather than
     Pallas' generic one: the generic rule drives the interpreter once per
@@ -144,8 +148,9 @@ def build_fused_step(costs: CostModel, g: GraphArrays, case: SweepCase,
     """
 
     @custom_vmap
-    def fused(st: SimState, g: GraphArrays, case: SweepCase) -> SimState:
-        leaves, treedef = jax.tree_util.tree_flatten((st, g, case))
+    def fused(st: SimState, g: GraphArrays, case: SweepCase,
+              tables: dlb.VictimTables) -> SimState:
+        leaves, treedef = jax.tree_util.tree_flatten((st, g, case, tables))
         n_st = len(jax.tree_util.tree_leaves(st))
         new = _pallas_step(leaves, treedef, n_st, costs, max_steps,
                            batch=False)
@@ -153,15 +158,15 @@ def build_fused_step(costs: CostModel, g: GraphArrays, case: SweepCase,
             jax.tree_util.tree_structure(st), new)
 
     @fused.def_vmap
-    def _fused_batched(axis_size, in_batched, st, g, case):
+    def _fused_batched(axis_size, in_batched, st, g, case, tables):
         def bcast(x, b):
             x = jnp.asarray(x)
             return x if b else jnp.broadcast_to(x[None],
                                                 (axis_size,) + x.shape)
 
-        stb, gb, cb = jax.tree_util.tree_map(
-            bcast, (st, g, case), tuple(in_batched))
-        leaves, treedef = jax.tree_util.tree_flatten((stb, gb, cb))
+        stb, gb, cb, tb = jax.tree_util.tree_map(
+            bcast, (st, g, case, tables), tuple(in_batched))
+        leaves, treedef = jax.tree_util.tree_flatten((stb, gb, cb, tb))
         n_st = len(jax.tree_util.tree_leaves(stb))
         new = _pallas_step(leaves, treedef, n_st, costs, max_steps,
                            batch=True)
@@ -170,6 +175,6 @@ def build_fused_step(costs: CostModel, g: GraphArrays, case: SweepCase,
         return out, jax.tree_util.tree_map(lambda _: True, out)
 
     def step(st: SimState) -> SimState:
-        return fused(st, g, case)
+        return fused(st, g, case, tables)
 
     return step

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import phases, taskgraph
+from repro.core import dlb, phases, taskgraph
 from repro.core.backends import BACKENDS, get_backend, resolve_name
 from repro.core.cache import ResultCache, case_key, graph_digest
 from repro.core.phases import REFERENCE_OPS
@@ -58,7 +58,8 @@ def _mid_run_state(g, case, k):
     st = init_state(g, CFG.n_workers, CFG.stack_cap, CFG.queue_cap, 4,
                     case.seed)
     step = get_backend("reference").build_step(
-        CFG.n_workers, CFG.stack_cap, CFG.costs, g, case, CFG.max_steps)
+        CFG.n_workers, CFG.stack_cap, CFG.costs, g, case, CFG.max_steps,
+        dlb.victim_tables(CFG.n_workers, case))
     return jax.lax.while_loop(lambda c: c[0] < k,
                               lambda c: (c[0] + 1, step(c[1])),
                               (jnp.int32(0), st))[1]
@@ -88,7 +89,8 @@ def test_each_phase_bitwise_per_backend(graph, pallas_ops, spec):
         st = both("adopt_phase", st, running)
         st = both("spawn_phase", st, running, g=g)
         st, task, ts, found = both("dequeue_phase", st, running, g=g)
-        st = both("thief_phase", st, found, running)
+        st = both("thief_phase", st, found, running,
+                  tables=dlb.victim_tables(CFG.n_workers, case))
         st = both("victim_phase", st, found, g=g)
         both("exec_phase", st, task, ts, found, g=g)
 

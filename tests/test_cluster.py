@@ -10,6 +10,7 @@ PRNG consumption of ``pick_victim`` never changes (two xorshifts per call).
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -21,6 +22,7 @@ from repro.core import barrier, cache, dlb, taskgraph, topology
 from repro.core.costs import DEFAULT_COSTS
 from repro.core.scheduler import CTR_NAMES, SimConfig
 from repro.core.spec import RuntimeSpec
+from repro.core.state import make_case
 from repro.core.sweep import CaseSpec, run_cases, run_grid
 from repro.core.topology import PRESETS, MachineTopology
 
@@ -237,6 +239,78 @@ def test_pick_victim_bandwidth_aware_strata():
     assert xnode_frac(TWO_NODE.arrays()) == 1.0
     f = xnode_frac(starved_t.arrays())
     assert 0.0 < f < 0.2, f             # expect ~1/16 cross-node
+
+
+# ---------------- victim-weight tables, built once per case ----------------
+#: (preset, padded width W, active workers): the flat model, a
+#: single-node hierarchy and a cluster with node tables, each at full width
+#: and with workers below the padded width
+TABLE_CASES = [(None, 16, 16), (None, 16, 11),
+               ("quad_socket_48", 48, 48), ("quad_socket_48", 48, 30),
+               ("rack_4x2x24", 48, 48), ("rack_4x2x24", 32, 20)]
+
+
+def _table_case(preset, n_w):
+    topo = None if preset is None else PRESETS[preset]
+    zsz = max(n_w // 4, 1) if topo is None else topo.zone_size_for(n_w)
+    return make_case(RuntimeSpec(balance="na_ws"), n_w, zsz, topology=topo)
+
+
+@pytest.mark.parametrize("preset,W,n_w", TABLE_CASES,
+                         ids=lambda v: str(v))
+def test_victim_tables_match_remote_weight_table(preset, W, n_w):
+    """The once-per-case tables are bitwise the tables the thief phase
+    built at every step: remote, node_local and node_remote pairs."""
+    case = _table_case(preset, n_w)
+    me = jnp.arange(W, dtype=jnp.int32)
+    tb = jax.jit(dlb.victim_tables, static_argnums=0)(W, case)
+    want = {
+        "remote": dlb.remote_weight_table(me, case.n_workers,
+                                          case.zone_size, case.topo),
+        **{r: dlb.remote_weight_table(me, case.n_workers, case.zone_size,
+                                      case.topo, restrict=r)
+           for r in ("node_local", "node_remote")}}
+    for name, (cum, total) in want.items():
+        got_cum, got_total = getattr(tb, name)
+        assert got_cum.shape == (W, W) and got_cum.dtype == jnp.int32
+        assert np.array_equal(np.asarray(got_cum), np.asarray(cum)), name
+        assert np.array_equal(np.asarray(got_total), np.asarray(total)), name
+    if preset == "rack_4x2x24":
+        # the cluster's split is real: both node tables carry candidates
+        assert (np.asarray(tb.node_local[1])[:n_w] > 0).all()
+        assert (np.asarray(tb.node_remote[1])[:n_w] > 0).all()
+
+
+def test_victim_tables_vmapped_over_mixed_cases():
+    """The batched executor builds every lane's tables under one ``vmap``
+    over the stacked cases; each lane equals its case built alone."""
+    W = 48
+    cases = [_table_case(p, n) for p, w, n in TABLE_CASES if w == W]
+    cases.append(_table_case(None, 40))
+    cb = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cases)
+    tb = jax.jit(jax.vmap(functools.partial(dlb.victim_tables, W)))(cb)
+    for i, case in enumerate(cases):
+        alone = jax.tree_util.tree_leaves(dlb.victim_tables(W, case))
+        lane = jax.tree_util.tree_leaves(tb)
+        assert len(lane) == len(alone) == 6
+        for x, y in zip(lane, alone):
+            assert np.array_equal(np.asarray(x)[i], np.asarray(y)), i
+
+
+def test_run_grid_rows_bitwise_across_executors_hierarchical():
+    """A short grid over the flat, single-node and cluster machines, with
+    workers below the padded width, gives the same rows under ``vmap``,
+    ``serial`` and ``sharded`` (each builds the tables its own way: per
+    lane before the vmapped loop, per case before the serial loop)."""
+    g = taskgraph.build("fib", n=8)
+    rows = {}
+    for strategy in ("vmap", "serial", "sharded"):
+        rows[strategy] = run_grid(
+            g, balancers=("na_ws", "na_rp"), n_workers=(12, 16),
+            p_local=(0.5,), t_interval=(5,), cfg=CFG, strategy=strategy,
+            cache=None, topologies=(None, "quad_socket_48", "rack_4x2x24"))
+    for strategy in ("serial", "sharded"):
+        _assert_bitwise(rows[strategy], rows["vmap"], strategy)
 
 
 # ---------------- ws_transfer payload pricing ----------------

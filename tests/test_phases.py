@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import arrivals as arrivals_mod
-from repro.core import phases, taskgraph
+from repro.core import dlb, phases, taskgraph
 from repro.core.backends import get_backend
 from repro.core.scheduler import SimConfig, graph_arrays
 from repro.core.spec import LATTICE, RuntimeSpec
@@ -64,7 +64,8 @@ def _advance(case, st, k_steps):
     traced case keeps one compilation across lattice points and worker
     counts, which is what makes the hypothesis sweep affordable."""
     step = get_backend("reference").build_step(
-        W, CFG.stack_cap, CFG.costs, GARR, case, CFG.max_steps)
+        W, CFG.stack_cap, CFG.costs, GARR, case, CFG.max_steps,
+        dlb.victim_tables(W, case))
     return jax.lax.while_loop(lambda c: c[0] < k_steps,
                               lambda c: (c[0] + 1, step(c[1])),
                               (jnp.int32(0), st))[1]
@@ -104,7 +105,8 @@ def check_phases_padded_inert(spec: RuntimeSpec, n_workers: int, seed: int,
     _assert_inert(st2, st3, n_workers, (*label, "dequeue"))
     # padded lanes never find work either
     assert not np.asarray(found)[n_workers:].any(), label
-    st4 = phases.thief_phase(st3, found, running, **kw)
+    st4 = phases.thief_phase(st3, found, running,
+                             tables=dlb.victim_tables(W, case), **kw)
     _assert_inert(st3, st4, n_workers, (*label, "thief"))
     st5 = phases.victim_phase(st4, found, g=GARR, **kw)
     _assert_inert(st4, st5, n_workers, (*label, "victim"))

@@ -13,7 +13,9 @@ no TPU library.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import numpy as np
@@ -86,8 +88,66 @@ def _compile_one_chip(topo, backend: str):
         cfg, gq_cap, *_placed(args, one_chip)).compile()
 
 
-def test_reference_run_batch_compiles_for_one_chip(topo, no_compile_cache):
-    compiled = _compile_one_chip(topo, "reference")
+@pytest.fixture(scope="module")
+def reference_one_chip(topo, no_compile_cache):
+    return _compile_one_chip(topo, "reference")
+
+
+def _computations(text: str) -> dict:
+    """The HLO text's computations: name -> instruction lines."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _while_body_lines(text: str) -> list:
+    """Instruction lines of every ``while`` body and of every computation
+    those bodies call (fusions, nested loops, reductions), transitively."""
+    comps = _computations(text)
+    callee = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+    todo = [m for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += [c for line in comps[name] for c in callee.findall(line)]
+    return [line for name in seen for line in comps[name]]
+
+
+def test_victim_tables_are_not_rebuilt_inside_the_loop(reference_one_chip):
+    """The thief's victim-weight tables are built once per case, before the
+    device loop: no instruction inside a ``while`` body that comes from the
+    thief phase is a gather with one element per (lane, thief, candidate)."""
+    body = _while_body_lines(reference_one_chip.as_text())
+    thief = [line for line in body if "vmap(thief)" in line]
+    assert thief, "no thief-phase instruction found in the loop body"
+    table_size = LANES * W * W
+    rebuilt = []
+    for line in thief:
+        out = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if out is None or op is None or op.group(1).split("/")[-1] != "gather":
+            continue
+        dims = [int(d) for d in out.group(1).split(",") if d]
+        if math.prod(dims) == table_size:
+            rebuilt.append(line[:160])
+    assert not rebuilt, rebuilt
+
+
+def test_reference_run_batch_compiles_for_one_chip(reference_one_chip):
+    compiled = reference_one_chip
     mem = compiled.memory_analysis()
     # the donated state aliases into the loop carry
     assert mem.alias_size_in_bytes > 0
