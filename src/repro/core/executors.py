@@ -126,11 +126,12 @@ class ExecContext:
     cfg: SimConfig                   # n_workers == the plan's w_pad
     gq_cap: int
     graphs: Sequence[TaskGraph]
-    garr: Sequence[GraphArrays]      # padded to the plan's t_pad
+    garr: Sequence[GraphArrays]      # host arrays padded to the plan's t_pad
     release_len: int = 1
     call: int = 0                    # the run_cases call (names its spans)
 
     def case_for(self, s: CaseSpec) -> SweepCase:
+        """The case of ``s``, with host (numpy) leaves."""
         if s.arrivals is None and self.release_len == 1:
             release = None
         else:
@@ -141,9 +142,9 @@ class ExecContext:
             s.spec, s.n_workers, s.zone_size, s.seed,
             round(float(self.graphs[s.graph].mem_bound), 3),
             make_params(s.n_victim, s.n_steal, s.t_interval, s.p_local,
-                        s.p_local_node),
+                        s.p_local_node, xp=np),
             topology=s.topology, release_ns=release,
-            closed=s.arrivals is None)
+            closed=s.arrivals is None, xp=np)
 
 
 def _init_body(cfg: SimConfig, gq_cap: int, gb, cb: SweepCase):
@@ -247,17 +248,22 @@ def _run_batch_sharded(cfg: SimConfig, gq_cap: int, n_dev: int, gb,
 
 def _stack_chunk(ctx: ExecContext, specs_chunk: Sequence[CaseSpec],
                  padded: int):
-    """Stack a chunk's graphs and cases, padding with inert lanes."""
+    """Stack a chunk's graphs and cases, padding with inert lanes.  Both
+    are stacked on the host and sent in one transfer: stacking the next
+    chunk puts no computation in the device queue behind the running
+    chunk, so it need not wait for that chunk to end."""
     cases = [ctx.case_for(s) for s in specs_chunk]
     garrs = [ctx.garr[s.graph] for s in specs_chunk]
     if padded > len(specs_chunk):
         # zero-task graph: the lane's running gate is false from step 0
-        inert = garrs[0]._replace(n_tasks=jnp.int32(0))
+        inert = garrs[0]._replace(n_tasks=np.int32(0))
         garrs = garrs + [inert] * (padded - len(specs_chunk))
         cases = cases + [cases[0]] * (padded - len(cases))
-    gb = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *garrs)
-    cb = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cases)
-    return gb, cb
+
+    def stack(parts):
+        return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *parts)
+
+    return jax.device_put((stack(garrs), stack(cases)))
 
 
 class Executor(abc.ABC):

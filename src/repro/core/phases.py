@@ -348,7 +348,8 @@ def spawn_phase(st: SimState, running, *, g: GraphArrays, case: SweepCase,
         g_ts = st.g_ts.at[gidx].set(clock, mode="drop")
         g_tail = st.g_tail + jnp.sum(act_g, dtype=jnp.int32)
 
-        xq, ok = ops.push(st.xq, me, tgt, task, clock, act_x)
+        with jax.named_scope("xq"):
+            xq, ok = ops.push(st.xq, me, tgt, task, clock, act_x)
         pushed_x = ok
         imm = act_x & ~ok
         rr = st.rr + (act_x & ~use_rp).astype(jnp.int32)
@@ -444,8 +445,9 @@ def dequeue_phase(st: SimState, running, *, g: GraphArrays, case: SweepCase,
 
     # --- XQueue lane: master queue then rotated aux scan
     idle_x = idle_m & m.uses_xq
-    xq, task_x, ts_x, src, found_x, checked = ops.pop_first(
-        st.xq, st.deq_rr, idle_x, n_w)
+    with jax.named_scope("xq"):
+        xq, task_x, ts_x, src, found_x, checked = ops.pop_first(
+            st.xq, st.deq_rr, idle_x, n_w)
     pay_x = jnp.where(found_x, g.payload[jnp.where(found_x, task_x, 0)], 0)
     cost_x = jnp.where(idle_x, checked * costs.c_cache, 0)
     cost_x = cost_x + jnp.where(found_x,
@@ -565,10 +567,12 @@ def victim_phase(st: SimState, found, *, g: GraphArrays, case: SweepCase,
     bw_vt = t.bw[topology_mod.domain_of(me, zsz, t.n_domains),
                  topology_mod.domain_of(thief, zsz, t.n_domains)]
     xfer_bw = jnp.where(t.cluster & (me != thief), bw_vt, 0).astype(jnp.int32)
-    xq, clock, stolen, src_empty, tgt_full, moved_bytes = dlb.ws_transfer(
-        st.xq, vm_ws, thief, params.n_steal, st.clock, comm_c,
-        st.deq_rr, WS_CAP, case.n_workers, payload=g.payload,
-        xfer_bw=xfer_bw)
+    with jax.named_scope("xq"):
+        xq, clock, stolen, src_empty, tgt_full, moved_bytes = \
+            dlb.ws_transfer(st.xq, vm_ws, thief, params.n_steal,
+                            st.clock, comm_c, st.deq_rr, WS_CAP,
+                            case.n_workers, payload=g.payload,
+                            xfer_bw=xfer_bw)
     same_d = _same_domain(me, thief, case)
     same_n = _same_node(me, thief, case)
     ctr = _bump(ops, st.ctr, "stolen", stolen)
@@ -701,7 +705,11 @@ def step_pipeline(st: SimState, *, g: GraphArrays, case: SweepCase,
     Each phase runs under a ``jax.named_scope`` of its short name
     (``adopt`` … ``exec``), the cluster occupancy charge under
     ``occupancy`` and :func:`run_gate` under ``gate``: HLO metadata only,
-    which a device trace reads as per-phase time.
+    which a device trace reads as per-phase time.  Inside the phases, the
+    three calls that touch the XQueue's ``(W, W, Q)`` arrays (the push in
+    spawn, the pop scan in dequeue, NA-WS's transfer in victim) run under
+    a nested ``xq`` scope, so the queue's share of those phases reads
+    apart.
 
     ``tables`` are the case's victim-weight tables
     (:func:`repro.core.dlb.victim_tables`), built by the step's driver

@@ -67,10 +67,10 @@ class Params(NamedTuple):
 
 
 def make_params(n_victim=4, n_steal=8, t_interval=100, p_local=1.0,
-                p_local_node=0.75) -> Params:
-    return Params(jnp.int32(n_victim), jnp.int32(n_steal),
-                  jnp.int32(t_interval), jnp.float32(p_local),
-                  jnp.float32(p_local_node))
+                p_local_node=0.75, xp=jnp) -> Params:
+    return Params(xp.int32(n_victim), xp.int32(n_steal),
+                  xp.int32(t_interval), xp.float32(p_local),
+                  xp.float32(p_local_node))
 
 
 class SweepCase(NamedTuple):
@@ -99,7 +99,8 @@ def make_case(spec: RuntimeSpec | str | int, n_workers: int, zone_size: int,
               seed: int = 0, mem_bound: float = 0.0,
               params: Params | None = None,
               topology: MachineTopology | str | None = None,
-              release_ns=None, closed: bool | None = None) -> SweepCase:
+              release_ns=None, closed: bool | None = None,
+              xp=jnp) -> SweepCase:
     """Lift a runtime configuration to traced scalars.
 
     ``spec`` accepts a :class:`RuntimeSpec`, a legacy mode name or spec
@@ -117,6 +118,11 @@ def make_case(spec: RuntimeSpec | str | int, n_workers: int, zone_size: int,
     arithmetic bitwise identical to the pre-arrival engine.  ``closed``
     may be forced ``True`` alongside a (zero) vector so closed and open
     cases stack with uniform shapes inside one vmapped chunk.
+
+    ``xp`` is the array module of the leaves.  ``numpy`` keeps the case
+    on the host, so that the executors stack a chunk's cases there and
+    send them in one transfer: each ``jax.numpy`` scalar is a computation
+    of its own on the device, queued behind whatever chunk is running.
     """
     if isinstance(spec, int):
         spec = MODE_SPECS[tuple(MODE_SPECS)[spec]]
@@ -125,19 +131,19 @@ def make_case(spec: RuntimeSpec | str | int, n_workers: int, zone_size: int,
     topo = topology_mod.resolve(topology)
     if closed is None:
         closed = release_ns is None
-    release = (jnp.zeros((1,), jnp.int32) if release_ns is None
-               else jnp.asarray(np.asarray(release_ns, np.int32)))
+    release = (xp.zeros((1,), xp.int32) if release_ns is None
+               else xp.asarray(np.asarray(release_ns, np.int32)))
     return SweepCase(
-        queue_id=jnp.int32(spec.queue_id),
-        barrier_id=jnp.int32(spec.barrier_id),
-        balance_id=jnp.int32(spec.balance_id),
-        n_workers=jnp.int32(n_workers),
-        zone_size=jnp.int32(zone_size), seed=jnp.int32(seed),
-        mem_bound=jnp.float32(mem_bound),
-        params=params if params is not None else make_params(),
-        topo=(topology_mod.degenerate_arrays() if topo is None
-              else topo.arrays()),
-        closed=jnp.asarray(bool(closed)),
+        queue_id=xp.int32(spec.queue_id),
+        barrier_id=xp.int32(spec.barrier_id),
+        balance_id=xp.int32(spec.balance_id),
+        n_workers=xp.int32(n_workers),
+        zone_size=xp.int32(zone_size), seed=xp.int32(seed),
+        mem_bound=xp.float32(mem_bound),
+        params=params if params is not None else make_params(xp=xp),
+        topo=(topology_mod.degenerate_arrays(xp) if topo is None
+              else topo.arrays(xp)),
+        closed=xp.asarray(bool(closed)),
         release_ns=release)
 
 
@@ -157,26 +163,28 @@ class GraphArrays(NamedTuple):
     payload: jax.Array    # (T,) int32 task payload in bytes (cluster D/B)
 
 
-def graph_arrays(graph: TaskGraph, pad_to: int | None = None) -> GraphArrays:
+def graph_arrays(graph: TaskGraph, pad_to: int | None = None,
+                 xp=jnp) -> GraphArrays:
     """Lift a host TaskGraph to device arrays, optionally padded to a common
-    length with inert tasks (dur 0, no children, no notify target)."""
+    length with inert tasks (dur 0, no children, no notify target).  With
+    ``xp=numpy`` the arrays stay on the host (see :func:`make_case`)."""
     T = graph.n_tasks
     P = max(pad_to or T, T)
 
     def pad(a, fill):
         a = np.asarray(a, np.int32)
         if P == T:
-            return jnp.asarray(a)
+            return xp.asarray(a)
         out = np.full(P, fill, np.int32)
         out[:T] = a
-        return jnp.asarray(out)
+        return xp.asarray(out)
 
     payload = (np.zeros(T, np.int32) if graph.payload is None
                else graph.payload)
     return GraphArrays(
         dur=pad(graph.dur, 0), first_child=pad(graph.first_child, 0),
         n_children=pad(graph.n_children, 0), notify=pad(graph.notify, -1),
-        join_dep=pad(graph.join_dep, 0), n_tasks=jnp.int32(T),
+        join_dep=pad(graph.join_dep, 0), n_tasks=xp.int32(T),
         payload=pad(payload, 0))
 
 

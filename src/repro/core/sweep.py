@@ -244,7 +244,7 @@ def _run_cases(graphs: List[TaskGraph], specs: List[CaseSpec],
             run_cfg = dataclasses.replace(cfg, n_workers=plan.w_pad)
             ctx = ExecContext(
                 cfg=run_cfg, gq_cap=plan.gq_cap, graphs=graphs,
-                garr=[graph_arrays(g, plan.t_pad) for g in graphs],
+                garr=[graph_arrays(g, plan.t_pad, xp=np) for g in graphs],
                 release_len=(plan.t_pad
                              if any(s.arrivals is not None for s in miss_specs)
                              else 1),

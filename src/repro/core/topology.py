@@ -248,10 +248,12 @@ class MachineTopology:
         return d
 
     # --- traced view ---
-    def arrays(self) -> TopoArrays:
+    def arrays(self, xp=jnp) -> TopoArrays:
         """Lift to the traced ``(DMAX, DMAX)``-padded pytree.  Padding
         rows/cols repeat the largest distance; they are unreachable (domain
-        ids clip to ``n_domains - 1``) so the fill never matters."""
+        ids clip to ``n_domains - 1``) so the fill never matters.  ``xp``
+        is the array module of the leaves: ``numpy`` keeps them on the
+        host (see :func:`repro.core.state.make_case`)."""
         fill = max(max(r) for r in self.dist)
         d = np.full((DMAX, DMAX), fill, np.int32)
         d[:self.n_sockets, :self.n_sockets] = np.asarray(self.dist, np.int32)
@@ -266,14 +268,14 @@ class MachineTopology:
         if self.bandwidth is not None:
             b[:self.n_sockets, :self.n_sockets] = np.asarray(
                 self.bandwidth, np.int32)
-        return TopoArrays(n_domains=jnp.int32(self.n_sockets),
-                          dist=jnp.asarray(d),
-                          flat=jnp.asarray(bool(self.is_flat)),
-                          node=jnp.asarray(node),
-                          bw=jnp.asarray(b),
-                          cluster=jnp.asarray(self.is_cluster),
-                          bneck_bw=jnp.int32(max(self.bottleneck_bw, 1)),
-                          bw_scale=jnp.float32(self.bw_scale))
+        return TopoArrays(n_domains=xp.int32(self.n_sockets),
+                          dist=xp.asarray(d),
+                          flat=xp.asarray(bool(self.is_flat)),
+                          node=xp.asarray(node),
+                          bw=xp.asarray(b),
+                          cluster=xp.asarray(self.is_cluster),
+                          bneck_bw=xp.int32(max(self.bottleneck_bw, 1)),
+                          bw_scale=xp.float32(self.bw_scale))
 
     # --- constructors ---
     @classmethod
@@ -313,17 +315,17 @@ class MachineTopology:
 #: TopoArrays for cases built without a topology: the flat model.  The
 #: matrix content is never read on the flat path (consumers use the legacy
 #: CostModel constants directly), only the shape must be right.
-def degenerate_arrays() -> TopoArrays:
-    return TopoArrays(n_domains=jnp.int32(1),
-                      dist=jnp.asarray(np.full((DMAX, DMAX),
-                                               DEFAULT_COSTS.c_numa,
-                                               np.int32)),
-                      flat=jnp.asarray(True),
-                      node=jnp.zeros(DMAX, jnp.int32),
-                      bw=jnp.ones((DMAX, DMAX), jnp.int32),
-                      cluster=jnp.asarray(False),
-                      bneck_bw=jnp.int32(1),
-                      bw_scale=jnp.float32(1.0))
+def degenerate_arrays(xp=jnp) -> TopoArrays:
+    return TopoArrays(n_domains=xp.int32(1),
+                      dist=xp.asarray(np.full((DMAX, DMAX),
+                                              DEFAULT_COSTS.c_numa,
+                                              np.int32)),
+                      flat=xp.asarray(True),
+                      node=xp.zeros(DMAX, xp.int32),
+                      bw=xp.ones((DMAX, DMAX), xp.int32),
+                      cluster=xp.asarray(False),
+                      bneck_bw=xp.int32(1),
+                      bw_scale=xp.float32(1.0))
 
 
 def _cluster_matrices(n_nodes: int, sockets_per_node: int,
@@ -350,6 +352,20 @@ def _cluster_matrices(n_nodes: int, sockets_per_node: int,
 _TWO_NODE = _cluster_matrices(2, 2)
 _RACK = _cluster_matrices(4, 2)
 
+
+def _nps4_matrix() -> Tuple[Tuple[int, ...], ...]:
+    """Two sockets of four NUMA nodes each (domains 0-3 and 4-7), as
+    ``numactl --hardware`` lists a 2-socket AMD EPYC 9004 in NPS4 mode:
+    SLIT 10 within a node and 32 across the sockets.  Between the nodes of
+    one socket the table takes a uniform 12; that is assumed, not read
+    from AMD's documents (some firmware gives 11 to the adjacent quadrant
+    and 12 to the diagonal one).  Scaled so that 10 is the cost model's
+    ``c_zone`` (30 ns): 30 within a node, 36 to the other nodes of the
+    socket, 96 across the sockets."""
+    return tuple(tuple(30 if i == j else 36 if i // 4 == j // 4 else 96
+                       for j in range(8))
+                 for i in range(8))
+
 #: canned presets matching the paper's evaluation machines (§V) plus the
 #: cluster tier above them: a single-socket workstation, a dual-socket
 #: Skylake-SP-class node, a quad-socket node where the interconnect is two
@@ -358,7 +374,11 @@ _RACK = _cluster_matrices(4, 2)
 #: carry both a latency and a bandwidth, sharing one bottleneck uplink.
 #: Distances follow the cost model's published-figure calibration
 #: (c_zone=30 intra-socket, c_numa=100 one QPI/UPI hop, 160 two hops,
-#: 500 a network round-trip).
+#: 500 a network round-trip).  ``epyc_2p_nps4`` is a many-core server
+#: whose domains are NUMA nodes rather than sockets: a 2-socket AMD EPYC
+#: 9654 (96 Zen 4 cores a socket) in the NPS4 mode of AMD's EPYC 9004 BIOS
+#: and Workload Tuning Guide, 8 nodes of 24 cores; its distances are a
+#: SLIT table scaled to c_zone, in part assumed (see :func:`_nps4_matrix`).
 PRESETS = {
     "uds": MachineTopology(
         name="uds", n_sockets=1, cores_per_socket=48,
@@ -383,6 +403,10 @@ PRESETS = {
         name="rack_4x2x24", n_sockets=8, cores_per_socket=24,
         n_nodes=4, dist=_RACK[0], bandwidth=_RACK[1],
         bottleneck_bw=32),
+    # 2 sockets x 4 NUMA nodes x 24 cores: 192 workers over 8 domains
+    "epyc_2p_nps4": MachineTopology(
+        name="epyc_2p_nps4", n_sockets=8, cores_per_socket=24,
+        dist=_nps4_matrix()),
 }
 
 

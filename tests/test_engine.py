@@ -16,6 +16,9 @@ the results:
   stalled lane produces are bitwise identical across executors.
 """
 
+import collections
+import contextlib
+import time
 import warnings
 
 import jax
@@ -85,6 +88,32 @@ def test_pipeline_toggle_is_bitwise_invisible(graph):
     off = run_cases(graph, _specs(), cfg=CFG, pipeline=False)
     for a, b in zip(_rows(on), _rows(off)):
         assert np.array_equal(a, b)
+
+
+def test_next_chunk_is_stacked_while_the_running_chunk_runs(monkeypatch):
+    """Stacking chunk k+1 puts nothing in the device queue behind chunk k,
+    so the host's time in ``repro.stack`` stays a small part of its time
+    waiting for the device.  (Built one device scalar at a time, stacking
+    waited for the running chunk to end, and the device idled while the
+    host stacked.)"""
+    spent = collections.Counter()
+
+    @contextlib.contextmanager
+    def timed(name, **args):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            spent[name] += time.perf_counter() - t0
+
+    g = taskgraph.fib(16)
+    specs = [CaseSpec(spec=b, n_workers=CFG.n_workers, n_zones=CFG.n_zones,
+                      seed=s) for b in ("na_rp", "na_ws") for s in range(2)]
+    run_cases(g, specs, cfg=CFG, strategy="batched")      # compile
+    monkeypatch.setattr(executors, "span", timed)
+    res = run_cases(g, specs, cfg=CFG, strategy="batched")
+    assert res.completed.all()
+    assert spent["stack"] < 0.25 * spent["wait"], dict(spent)
 
 
 def test_engine_stats_accounting(graph):

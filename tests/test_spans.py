@@ -18,6 +18,7 @@ import subprocess
 import sys
 import tempfile
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -132,7 +133,7 @@ def loop_locations():
     specs = _specs()[:2]
     plan = build_plan([g], specs)
     ctx = executors.ExecContext(cfg=cfg, gq_cap=plan.gq_cap, graphs=[g],
-                                garr=[graph_arrays(g, plan.t_pad)])
+                                garr=[graph_arrays(g, plan.t_pad, xp=np)])
     gb, cb = executors._stack_chunk(ctx, specs, 2)
     st = executors._init_batch(cfg, plan.gq_cap, gb, cb)
     text = executors._run_batch.lower(cfg, plan.gq_cap, gb, cb, st).as_text(

@@ -68,7 +68,7 @@ def _chunk(backend: str):
                       topology="quad_socket_48") for s in range(LANES)]
     plan = build_plan([g], specs)
     ctx = executors.ExecContext(cfg=cfg, gq_cap=plan.gq_cap, graphs=[g],
-                                garr=[graph_arrays(g, plan.t_pad)])
+                                garr=[graph_arrays(g, plan.t_pad, xp=np)])
     gb, cb = executors._stack_chunk(ctx, specs, LANES)
     st = jax.eval_shape(
         functools.partial(executors._init_body, cfg, plan.gq_cap), gb, cb)
